@@ -100,19 +100,21 @@ def _block(p, x, n_heads, causal=False, lora=None):
                                    alpha=LORA_SCALE * r, rank=r)
         return lora_lib.linear(h, p[name])
 
-    h = _ln(x, p["ln1"])
-    q = lin("wq", h).reshape(B, S, n_heads, dh)
-    k = lin("wk", h).reshape(B, S, n_heads, dh)
-    v = lin("wv", h).reshape(B, S, n_heads, dh)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(float(dh))
-    if causal:
-        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -1e30)
-    a = jax.nn.softmax(s, -1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", a, v).reshape(B, S, d)
-    x = x + lin("wo", o)
-    h = _ln(x, p["ln2"])
-    return x + lora_lib.linear(jax.nn.gelu(
-        lora_lib.linear(h, p["wu"])), p["wd"])
+    with jax.named_scope("attn"):
+        h = _ln(x, p["ln1"])
+        q = lin("wq", h).reshape(B, S, n_heads, dh)
+        k = lin("wk", h).reshape(B, S, n_heads, dh)
+        v = lin("wv", h).reshape(B, S, n_heads, dh)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(float(dh))
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -1e30)
+        a = jax.nn.softmax(s, -1)
+        o = jnp.einsum("bhqk,bkhd->bqhd", a, v).reshape(B, S, d)
+        x = x + lin("wo", o)
+    with jax.named_scope("mlp"):
+        h = _ln(x, p["ln2"])
+        return x + lora_lib.linear(jax.nn.gelu(
+            lora_lib.linear(h, p["wu"])), p["wd"])
 
 
 def _run_blocks(blocks, x, n_heads, causal, lora=None):

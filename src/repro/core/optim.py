@@ -34,10 +34,12 @@ def adam_init(params) -> AdamState:
                      jax.tree.map(jnp.copy, zeros))
 
 
+@jax.named_scope("adam")
 def adam_update(grads, state: AdamState, params, *, lr, b1=0.9, b2=0.999,
                 eps=1e-8, weight_decay=0.0, grad_clip=0.0):
     """Returns (new_params, new_state). ``lr`` may be a float or a
-    ``step -> lr`` schedule callable."""
+    ``step -> lr`` schedule callable. Its ops sit in the ``adam`` scope,
+    the grad clip included."""
     step = state.step + 1
     if grad_clip:
         gnorm = global_norm(grads)

@@ -454,13 +454,15 @@ class CohortEngine:
         opt = optim.adam_init(tr)
 
         def grad_fn(t, ixt):
-            bx, by = staged[ixt], labs[ixt]
+            with jax.named_scope("forward"):
+                bx, by = staged[ixt], labs[ixt]
 
             def loss_fn(tt):
-                logits = client_logits(frozen, ccfg, tt, bx, class_emb,
-                                       use_lora=use_lora)
-                return (losses.cross_entropy(logits, by),
-                        losses.accuracy(logits, by))
+                with jax.named_scope("forward"):
+                    logits = client_logits(frozen, ccfg, tt, bx, class_emb,
+                                           use_lora=use_lora)
+                    return (losses.cross_entropy(logits, by),
+                            losses.accuracy(logits, by))
 
             (loss, acc), g = jax.value_and_grad(
                 loss_fn, has_aux=True)(t)
@@ -502,10 +504,12 @@ class CohortEngine:
                 out_specs=rows, check_vma=False)
         after, loss, acc = train(global_tr, frozen, class_emb, staged,
                                  labs, idx, *het)
-        delta = jax.tree.map(
-            lambda a, g: (a - g[None]).astype(jnp.float32),
-            after, global_tr)
-        return comm_quantize_stacked(delta, self.cfg.strategy), loss, acc
+        with jax.named_scope("uplink"):
+            delta = jax.tree.map(
+                lambda a, g: (a - g[None]).astype(jnp.float32),
+                after, global_tr)
+            delta = comm_quantize_stacked(delta, self.cfg.strategy)
+        return delta, loss, acc
 
     def _aggregate(self, global_tr, weights, delta):
         """In-program FedAvg. Unsharded engines keep the flat
@@ -516,10 +520,11 @@ class CohortEngine:
         cross the mesh — so the stacked delta is never reduced on one
         device. Tree == flat within fp tolerance (re-association),
         pinned by the hypothesis property in tests/test_runtime.py."""
-        if self.shards > 1:
-            return server.aggregate_tree(global_tr, weights, delta,
-                                         n_shards=self.shards)
-        return server.aggregate_stacked(global_tr, weights, delta)
+        with jax.named_scope("fedavg"):
+            if self.shards > 1:
+                return server.aggregate_tree(global_tr, weights, delta,
+                                             n_shards=self.shards)
+            return server.aggregate_stacked(global_tr, weights, delta)
 
     def _build_round(self):
         def round_fn(global_tr, idx, pool_staged, pool_labs, weights,

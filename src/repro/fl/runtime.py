@@ -65,9 +65,20 @@ simulator's metric-ring flushes — counts into the module-level
 ``SYNC_TRACES`` ledger (the ``KERNEL_TRACES`` pattern), so tests and
 the CI smoke can assert a pipelined steady-state round performs zero
 host syncs rather than silently degenerating to the serial loop.
+
+**Names in a profile.** Every program runs inside a
+``jax.named_scope`` named by its kind, so each device op of a profiled
+run carries the kind in its op name (``jit(round_fn)/full_round/...``).
+On the host the runtime opens ``jax.profiler.TraceAnnotation`` spans at
+the points its counters already mark: ``runtime.compile:<kind>`` on a
+cache miss, ``runtime.dispatch:<kind>`` around each dispatch, and
+``runtime.sync:<tag>`` where ``SYNC_TRACES`` counts a host sync. They
+land on the device trace's clock and cost nothing while no profile is
+being taken.
 """
 from __future__ import annotations
 
+import functools
 import time
 import weakref
 from collections import OrderedDict
@@ -90,6 +101,21 @@ def sync_count(tag: str, n: int = 1) -> None:
 
 def reset_sync_traces() -> None:
     SYNC_TRACES.clear()
+
+
+def span(name: str):
+    """A host span in the profiler's trace (free while none is taken)."""
+    return jax.profiler.TraceAnnotation(name)
+
+
+def scoped(kind: str, fn: Callable) -> Callable:
+    """``fn`` traced inside ``jax.named_scope(kind)``, keeping its name
+    (and so the compiled module's name)."""
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with jax.named_scope(kind):
+            return fn(*args, **kwargs)
+    return run
 
 # Cohort-width buckets below this floor are not worth separate programs:
 # a width-4 program over a width-2 selection wastes two masked rows of a
@@ -198,7 +224,8 @@ class Handle:
         if not self._done:
             sync_count("handle_wait")
             sync_count(f"handle_wait:{self.kind}")
-            jax.block_until_ready(jax.tree.leaves(self._out))
+            with span(f"runtime.sync:handle_wait:{self.kind}"):
+                jax.block_until_ready(jax.tree.leaves(self._out))
             self._done = True
             if self._runtime is not None and self._hazard_ids:
                 self._runtime._clear_hazards(self._hazard_ids)
@@ -289,8 +316,9 @@ class ProgramRuntime:
         exe = self._exes.get(key)
         if exe is None:
             t0 = time.perf_counter()
-            exe = jax.jit(build(), donate_argnums=donate) \
-                .lower(*args).compile()
+            with span(f"runtime.compile:{kind}"):
+                exe = jax.jit(scoped(kind, build()),
+                              donate_argnums=donate).lower(*args).compile()
             dt = time.perf_counter() - t0
             self._exes[key] = exe
             k = self._kinds.setdefault(
@@ -386,7 +414,8 @@ class ProgramRuntime:
                          for a in args)
         self._prune_hazards()
         self._check_hazards(args, kind)
-        out = self.compile(kind, build, args, **kw)(*args)
+        with span(f"runtime.dispatch:{kind}"):
+            out = self.compile(kind, build, args, **kw)(*args)
         donate = tuple(kw.get("donate_argnums", ()))
         hazard_ids = self._register_hazards(args, donate, kind) \
             if donate else ()
@@ -399,9 +428,10 @@ class ProgramRuntime:
         blocking point in the pipelined loop goes through. Non-array
         leaves pass through untouched."""
         sync_count(tag)
-        jax.block_until_ready([
-            l for l in jax.tree.leaves(tree)
-            if hasattr(l, "block_until_ready")])
+        with span(f"runtime.sync:{tag}"):
+            jax.block_until_ready([
+                l for l in jax.tree.leaves(tree)
+                if hasattr(l, "block_until_ready")])
         return tree
 
     def clear(self):

@@ -309,6 +309,7 @@ class Model:
         decode = mode == "decode"
         aux = jnp.zeros((), jnp.float32)
 
+        @jax.named_scope("attn")
         def attn_part(x):
             xin = L.rms_norm(x, p["ln1"])
             if decode:
@@ -392,16 +393,18 @@ class Model:
                                                        dtype=jnp.int32)} \
                     if mode == "prefill" else None
             x = x + h
-        if fam == "moe" and "moe" in p:
-            y, aux = moe_lib.moe_ffn(p["moe"], L.rms_norm(x, p["ln2"]), cfg)
-            if cfg.n_shared_experts:
-                y = y + L.mlp(p["shared"], L.rms_norm(x, p["ln2"]), cfg,
-                              kind="swiglu")
-            x = x + y
-        else:
-            kind_mlp = "swiglu" if fam == "moe" else cfg.mlp
-            x = x + L.mlp(p, L.rms_norm(x, p["ln2"]), cfg, lora=lo,
-                          kind=kind_mlp)
+        with jax.named_scope("mlp"):
+            if fam == "moe" and "moe" in p:
+                y, aux = moe_lib.moe_ffn(p["moe"], L.rms_norm(x, p["ln2"]),
+                                         cfg)
+                if cfg.n_shared_experts:
+                    y = y + L.mlp(p["shared"], L.rms_norm(x, p["ln2"]), cfg,
+                                  kind="swiglu")
+                x = x + y
+            else:
+                kind_mlp = "swiglu" if fam == "moe" else cfg.mlp
+                x = x + L.mlp(p, L.rms_norm(x, p["ln2"]), cfg, lora=lo,
+                              kind=kind_mlp)
         return x, entry, aux
 
     # ---------------------------------------------------------- forward
@@ -511,7 +514,12 @@ class Model:
         return logits, aux
 
     # ---------------------------------------------------------- training
+    @jax.named_scope("forward")
     def loss_fn(self, frozen, trainable, batch):
+        """Its ops sit in the ``forward`` scope: under ``value_and_grad``
+        the forward's op names carry ``jvp(forward)``, the backward's
+        ``transpose(jvp(forward))``, a remat recompute's
+        ``rematted_computation``."""
         logits, aux = self.forward(frozen, trainable, batch)
         mask = batch.get("mask")
         ce = losses.cross_entropy(logits, batch["labels"], mask)
