@@ -11,6 +11,22 @@ alongside the main ``(bm, bn)`` accumulator), and B joins at the final
 K tile with one tiny ``(r, bn)`` gemm before the flush. All accumulation
 is fp32.
 
+Precision. Every product is the one a float32 gemm at ``HIGHEST`` forms.
+A bfloat16 ``x`` (the trunk's activations) is exact in one bf16 term, so
+of HIGHEST's six bf16 MXU passes three multiply zeros; the kernel keeps
+the x tile in bf16 and splits the dequantized f32 weight tile (and A)
+into three bf16 terms (``split_bf16x3``): ``x@w_hi + x@w_mid + x@w_lo``,
+three passes of exact bf16 x bf16 products accumulated in f32. Three
+terms because a dequantized weight carries a full 24-bit mantissa (an
+NF4 code times an f32 absmax): two terms would cut it to 16 bits. Any
+other ``x`` dtype takes the float32 HIGHEST route.
+
+``x @ A`` depends only on the row tile and the K tile, so the grid
+(``mi, ni, ki``, ``ki`` minormost) accumulates it into the ``(bm, r)``
+scratch during the ``ni == 0`` sweep only, and every later column tile
+of the row reuses it; ``ni`` and ``ki`` are declared ``arbitrary`` so no
+megacore split can reorder them.
+
 ``quant_matmul_t`` is the backward-pass companion: ``g @ dequant(W)ᵀ``
 through the same streamed tiles (grid minormost over the N blocks, the
 output tile indexed by K tile), which is the ``dx``-through-Wᵀ
@@ -37,25 +53,70 @@ from repro.kernels.quant_matmul import (HIGHEST, dequant_tile,
 
 
 def _dot(a, b, contract):
+    """f32 x f32 at HIGHEST: both operands split into three bf16 terms,
+    six MXU passes, every product exact and accumulated in f32."""
     return jax.lax.dot_general(a, b, (contract, ((), ())),
                                precision=HIGHEST,
                                preferred_element_type=jnp.float32)
 
 
+def _top16(v):
+    """f32 ``v`` cut to its top 16 bits (sign, exponent, 7 mantissa
+    bits): a value bf16 holds exactly."""
+    u = jax.lax.bitcast_convert_type(v, jnp.uint32)
+    return jax.lax.bitcast_convert_type(u & jnp.uint32(0xFFFF0000),
+                                        jnp.float32)
+
+
+def split_bf16x3(w):
+    """f32 ``w`` as bf16 ``(hi, mid, lo)`` whose f32 sum is ``w``
+    exactly: ``hi`` and ``mid`` each cut 8 of the 24 mantissa bits off
+    what is left, every remainder is exact in f32, and the last 8 bits
+    fit ``lo``. Cutting rather than rounding matches the chip's own
+    HIGHEST split: on a v5e the three-pass products agree with the f32
+    HIGHEST route bitwise."""
+    hi = _top16(w)
+    r = w - hi
+    mid = _top16(r)
+    return (hi.astype(jnp.bfloat16), mid.astype(jnp.bfloat16),
+            (r - mid).astype(jnp.bfloat16))
+
+
+def _dot_bf16x3(x, w):
+    """bf16 ``x (m, k)`` @ f32 ``w (k, n)`` in three bf16 passes: the
+    products HIGHEST forms for an ``x`` that bf16 holds exactly."""
+    p_hi, p_mid, p_lo = (jax.lax.dot_general(
+        x, t, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) for t in split_bf16x3(w))
+    return p_hi + (p_mid + p_lo)
+
+
 def _lora_kernel(x_ref, q_ref, s_ref, a_ref, b_ref, o_ref, acc_ref, h_ref,
                  *, bits, mode, nk, scale):
-    ki = pl.program_id(2)
+    ni, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when((ni == 0) & (ki == 0))
+    def _init_h():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[...].astype(jnp.float32)              # (bm, gk·block)
+    x = x_ref[...]                                  # (bm, gk·block)
+    if x.dtype == jnp.bfloat16:
+        mm = _dot_bf16x3
+    else:
+        x = x.astype(jnp.float32)
+        mm = functools.partial(_dot, contract=((1,), (0,)))
     w = dequant_tile(q_ref, s_ref, bits=bits, mode=mode)
-    acc_ref[...] += _dot(x, w, ((1,), (0,)))
-    # (bm, r) += x @ A over this K tile
-    h_ref[...] += _dot(x, a_ref[...].astype(jnp.float32), ((1,), (0,)))
+    acc_ref[...] += mm(x, w)
+
+    @pl.when(ni == 0)
+    def _h():
+        # (bm, r) += x @ A over this K tile; complete at the end of the
+        # row's first column sweep, reused by every later ni
+        h_ref[...] += mm(x, a_ref[...].astype(jnp.float32))
 
     @pl.when(ki == nk - 1)
     def _flush():
@@ -71,7 +132,9 @@ def lora_matmul(x, qt: QTensor, a, b, *, scale: float, block_m=256,
     """``x (..., K) @ dequant(qt (K, N)) + scale·(x@A)@B -> (..., N)``
     in one kernel. ``qt`` may cover a K zero-padded to a block multiple
     (the odd-K ``blockwise_quant`` contract) — x and A zero-pad rows to
-    match, which contracts identically. ``a``: (K, r); ``b``: (r, N)."""
+    match, which contracts identically. ``a``: (K, r); ``b``: (r, N).
+    A bfloat16 ``x`` takes the three-pass route, any other dtype the
+    f32 HIGHEST one (module docstring); the output is ``x.dtype``."""
     *lead, K = x.shape
     M = math.prod(lead)
     x2 = x.reshape(M, K)
@@ -113,6 +176,8 @@ def lora_matmul(x, qt: QTensor, a, b, *, scale: float, block_m=256,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, r), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(x2, qv, sv, a2, b2)
     return out[:M, :N].reshape(*lead, N)

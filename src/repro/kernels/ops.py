@@ -199,6 +199,8 @@ def _lora_fwd_impl(scale, x, w, a, b):
         bm, bn = autotune.lookup("lora_matmul", M, K, N, bits=w.bits,
                                  mode=w.mode)
         trace_count("lora_matmul_pallas")
+        if x.dtype == jnp.bfloat16:
+            trace_count("lora_matmul_pallas_bf16x3")
         return lk.lora_matmul(x, w, a, b, scale=scale, block_m=bm,
                               block_n=bn, interpret=_interpret())
     trace_count("lora_matmul_ref")
@@ -225,8 +227,11 @@ def _lora_fwd(scale, x, w, a, b):
 
 
 def _mm(a, b):
-    """float32 gemm at full precision: the fused op accumulates in fp32
-    on every backend, and a TPU's default rounds operands to bfloat16."""
+    """float32 gemm at full precision, for the backward's products: the
+    fused op accumulates in fp32 on every backend, and a TPU's default
+    rounds operands to bfloat16. (The Pallas forward keeps a bf16 x in
+    bf16 and forms the same products in three passes,
+    ``kernels.lora_matmul``.)"""
     return jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
 
 

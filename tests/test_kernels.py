@@ -1,6 +1,8 @@
 """Per-kernel validation: Pallas (interpret mode — executes the kernel body
 on CPU) vs the pure-jnp oracle in kernels/ref.py, swept over shapes,
 dtypes, GQA ratios, masks, and quantization modes."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -222,7 +224,8 @@ def test_decode_attention_partial_combine(rng):
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import autotune, ops as kops
-from repro.kernels.lora_matmul import lora_matmul, quant_matmul_t
+from repro.kernels.lora_matmul import (lora_matmul, quant_matmul_t,
+                                       split_bf16x3)
 
 
 def _lora_chain(x, qt, a, b, scale):
@@ -256,6 +259,97 @@ def test_fused_lora_kernel_vs_chain_forward(bits, mode, K, N, r, dtype,
         1.0, float(jnp.abs(want.astype(jnp.float32)).max()))
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=tol)
+
+
+@pytest.mark.parametrize("values", ["random", "nf4_scaled", "zero_pad"])
+def test_split_bf16x3_is_exact(values, rng):
+    """The bf16 route's weight split loses nothing: the three terms sum
+    back to the f32 value exactly (two terms would not)."""
+    if values == "random":
+        w = rng.randn(512, 256) * np.exp2(rng.randint(-20, 20, (512, 256)))
+    elif values == "nf4_scaled":       # dequantized NF4: code x absmax
+        codes = np.asarray(qlib.NF4_CODE, np.float32)
+        w = codes[rng.randint(0, 16, (512, 256))] * \
+            np.abs(rng.randn(512, 1)).astype(np.float32)
+    else:                              # pad groups carry zero scales
+        w = np.zeros((512, 256))
+    w = jnp.asarray(w, jnp.float32)
+    terms = split_bf16x3(w)
+    assert all(t.dtype == jnp.bfloat16 for t in terms)
+    hi, mid, lo = (t.astype(jnp.float32) for t in terms)
+    np.testing.assert_array_equal(np.asarray(hi + mid + lo), np.asarray(w))
+    if values != "zero_pad":
+        assert bool(jnp.any(hi + mid != w))
+
+
+@pytest.mark.parametrize("bits,mode", [(8, "linear"), (4, "nf4")])
+@pytest.mark.parametrize("M,K,N,block", [(40, 384, 96, 128),
+                                         (33, 256, 160, 64)])
+def test_fused_lora_bf16_route_matches_highest(bits, mode, M, K, N, block,
+                                               rng):
+    """A bf16 x takes the three-pass route; the same values as f32 take
+    HIGHEST. Same products, so the bf16 outputs agree bitwise nearly
+    everywhere, and everywhere within one bf16 ulp plus what the two
+    f32 accumulation orders may differ by (which is more than an ulp
+    only where the sum nearly cancels). N spans several column tiles
+    (x@A reused for ni > 0), K several K tiles."""
+    scale = 2.0
+    x = jnp.asarray(rng.randn(M, K), jnp.bfloat16)
+    w = jnp.asarray(rng.randn(K, N), jnp.float32)
+    a = jnp.asarray(rng.randn(K, 8) * 0.3, jnp.float32)
+    b = jnp.asarray(rng.randn(8, N) * 0.3, jnp.float32)
+    qt = ref.blockwise_quant(w, bits=bits, block=block, mode=mode)
+    run = functools.partial(lora_matmul, qt=qt, a=a, b=b, scale=scale,
+                            block_m=16, block_n=32, interpret=True)
+    got = run(x)
+    want = run(x.astype(jnp.float32)).astype(jnp.bfloat16)
+    assert got.dtype == jnp.bfloat16
+    got, want = (np.asarray(v, np.float32) for v in (got, want))
+    assert (got == want).mean() >= 0.99
+    ax = np.abs(np.asarray(x, np.float64))
+    aw = np.abs(np.asarray(qlib.dequantize(qt, jnp.float32), np.float64))
+    mag = ax @ aw[:K] + scale * (ax @ np.abs(np.asarray(a, np.float64))) \
+        @ np.abs(np.asarray(b, np.float64))
+    reorder = 2 * (K + 16) * 2.0 ** -24 * mag     # both routes' f32 sums
+    ulp = np.exp2(np.floor(np.log2(np.maximum(np.abs(want), 2.0 ** -126)))
+                  - 7)
+    assert np.all(np.abs(got - want) <= ulp + reorder)
+
+
+@pytest.mark.parametrize("bits,mode", [(8, "linear"), (4, "nf4")])
+def test_fused_lora_f32_route_reuses_h_across_column_tiles(bits, mode,
+                                                           rng):
+    """The f32 route, N over five column tiles and K over three K
+    tiles: every ni's flush carries the full x@A of its row tile, so
+    the output matches the chain at 1e-5 with a LoRA term that is not
+    small against the base."""
+    M, K, N, scale = 24, 384, 160, 2.0
+    x = jnp.asarray(rng.randn(M, K), jnp.float32)
+    w = jnp.asarray(rng.randn(K, N), jnp.float32)
+    a = jnp.asarray(rng.randn(K, 8) * 0.5, jnp.float32)
+    b = jnp.asarray(rng.randn(8, N) * 0.5, jnp.float32)
+    qt = ref.blockwise_quant(w, bits=bits, block=128, mode=mode)
+    want = _lora_chain(x, qt, a, b, scale)
+    got = lora_matmul(x, qt, a, b, scale=scale, block_m=8, block_n=32,
+                      interpret=True)
+    tol = 1e-5 * max(1.0, float(jnp.abs(want).max()))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_lora_op_counts_bf16x3_route(dtype, rng, monkeypatch):
+    """ops counts the three-pass route at trace time for bf16 x only."""
+    monkeypatch.setattr(kops, "_FORCE", "interpret")
+    x = jnp.asarray(rng.randn(9, 128), dtype)
+    qt = ref.blockwise_quant(jnp.asarray(rng.randn(128, 64), jnp.float32),
+                             bits=4, block=64, mode="nf4")
+    a = jnp.zeros((128, 4), jnp.float32)
+    b = jnp.zeros((4, 64), jnp.float32)
+    kops.reset_kernel_traces()
+    jax.eval_shape(lambda x: kops.lora_matmul(x, qt, a, b, scale=2.0), x)
+    assert kops.KERNEL_TRACES.get("lora_matmul_pallas", 0) == 1
+    assert kops.KERNEL_TRACES.get("lora_matmul_pallas_bf16x3", 0) == \
+        (1 if dtype == jnp.bfloat16 else 0)
 
 
 @pytest.mark.parametrize("bits,mode", [(8, "linear"), (4, "nf4")])

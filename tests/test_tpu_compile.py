@@ -83,6 +83,26 @@ def test_nf4_lora_kernels_compile(kernel, N, one_chip):
                        _spec((M_TRAIN, N), jnp.float32, one_chip), qt)
 
 
+@pytest.mark.parametrize("bits,mode", [(4, "nf4"), (8, "linear")])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_lora_matmul_routes_compile_as_lora_matmul(dtype, bits, mode,
+                                                    one_chip):
+    """Both routes of the fused forward (three bf16 passes for a bf16 x,
+    HIGHEST for f32) compile for v5e at the trunk's widest projection,
+    and the instruction keeps the name the roofline reader matches."""
+    K, N = 768, 3072
+    compiled = jax.jit(
+        lambda x, q, a, b: lm_k.lora_matmul(x, q, a, b, scale=2.0)).lower(
+        _spec((M_TRAIN, K), dtype, one_chip),
+        _qspec(K, N, bits, mode, one_chip),
+        _spec((K, RANK), jnp.float32, one_chip),
+        _spec((RANK, N), jnp.float32, one_chip)).compile()
+    names = [line.split(" = ")[0].split()[-1].lstrip("%")
+             for line in compiled.as_text().splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert [n.rpartition(".")[0] or n for n in names] == ["lora_matmul"]
+
+
 @pytest.mark.parametrize("bits,mode,K", [(8, "linear", 768),
                                          (4, "linear", 768),
                                          (4, "nf4", 768),
@@ -201,6 +221,7 @@ def test_trunk_step_kernels_keep_names_under_phase_scopes(one_chip,
     tok = _spec((2, 128), jnp.int32, one_chip)
     batch = {"tokens": tok, "labels": tok,
              "mask": _spec((2, 128), jnp.float32, one_chip)}
+    kops.reset_kernel_traces()
     text = jax.jit(model.train_step).lower(
         on_chip(params["frozen"]), on_chip(params["trainable"]),
         on_chip(jax.eval_shape(optim.adam_init, params["trainable"])),
@@ -213,6 +234,8 @@ def test_trunk_step_kernels_keep_names_under_phase_scopes(one_chip,
             calls.setdefault(name.rpartition(".")[0], []).append(path)
     assert set(calls) == {"lora_matmul", "quant_matmul_t",
                           "flash_attention"}
+    # the bf16 residual stream takes the forward's three-pass route
+    assert kops.KERNEL_TRACES.get("lora_matmul_pallas_bf16x3", 0) > 0
 
     def where(path):
         if "/rematted_computation/" in path:
