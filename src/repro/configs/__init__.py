@@ -3,7 +3,8 @@
 ``get_config(arch_id)`` returns the exact assigned configuration;
 ``get_reduced(arch_id)`` returns the CPU smoke-test variant of the same
 family. ``ARCHS`` lists the 10 assigned architectures (clip-b32 — the
-paper's own backbone — is additionally registered).
+paper's own backbone — and jamba2-mini, the hybrid SSM/MoE client on the
+chip, are additionally registered).
 """
 from __future__ import annotations
 
@@ -23,9 +24,11 @@ _MODULES = {
     "kimi-k2-1t-a32b": "kimi_k2_1t_a32b",
     "starcoder2-15b": "starcoder2_15b",
     "clip-b32": "clip_b32",
+    "jamba2-mini": "jamba2_mini",
 }
 
-ARCHS = tuple(k for k in _MODULES if k != "clip-b32")
+# the assigned ten; clip-b32 and jamba2-mini are registered besides
+ARCHS = tuple(k for k in _MODULES if k not in ("clip-b32", "jamba2-mini"))
 
 
 def _module(arch: str):

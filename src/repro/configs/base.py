@@ -7,6 +7,7 @@ smoke-test variant (≤2 layers, d_model ≤ 512, ≤4 experts).
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
@@ -19,7 +20,7 @@ SSM = "ssm"
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str  # dense | moe | ssm | hybrid | encdec | vlm
+    family: str  # dense | moe | ssm | hybrid | hybrid_moe | encdec | vlm
     n_layers: int
     d_model: int
     n_heads: int
@@ -34,7 +35,8 @@ class ModelConfig:
     window: Optional[int] = None          # sliding-window size (None = full)
     attn_pattern: Tuple[str, ...] = (ATTN,)  # repeating per-layer pattern
     use_rope: bool = True                 # False -> learned absolute pos emb
-    max_pos: int = 0                      # needed when use_rope=False
+    max_pos: int = 0                      # its rows; 0 with use_rope=False
+                                          # -> no positions at all (Jamba)
     mlp: str = "swiglu"                   # swiglu | gelu
 
     # MoE
@@ -44,6 +46,9 @@ class ModelConfig:
     first_k_dense: int = 0
     dense_d_ff: int = 0                   # d_ff of the first_k_dense layers
     capacity_factor: float = 1.25
+    router_renorm: bool = True            # renormalize the top-k gates
+    expert_period: int = 1                # layer i has experts iff
+    expert_offset: int = 0                # i % period == offset (hybrid_moe)
     moe_dispatch_bits: int = 0            # 0 | 8: int8 all-to-all payloads
                                           # (DeepSeek-V3-style low-precision
                                           # dispatch — beyond-paper §Perf)
@@ -53,6 +58,8 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_conv: int = 4
     ssm_dt_rank: int = 0                  # 0 -> ceil(d_model/16)
+    ssm_inner_norms: bool = False         # RMSNorm on dt, B, C (Jamba)
+    ssm_conv_bias: bool = False
 
     # hybrid (RG-LRU)
     lru_width: int = 0
@@ -111,6 +118,15 @@ class ModelConfig:
         pat = self.attn_pattern
         return tuple(pat[i % len(pat)] for i in range(self.n_layers))
 
+    def expert_layer(self, i: int) -> bool:
+        """Whether layer ``i``'s feed-forward is the expert layer."""
+        return i % self.expert_period == self.expert_offset
+
+    @property
+    def period(self) -> int:
+        """Layers in one period of the mixer and expert patterns."""
+        return math.lcm(len(self.attn_pattern), self.expert_period)
+
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
@@ -126,15 +142,29 @@ class ModelConfig:
             per_mlp = lambda ff: 3 * d * ff
         else:
             per_mlp = lambda ff: 2 * d * ff
+        di, N, R = self.d_inner, self.ssm_state, self.dt_rank
+        per_ssm = d * 2 * di + di * self.ssm_conv + di * (R + 2 * N) + \
+            R * di + di * N + 2 * di + di * d
+        if self.ssm_inner_norms:
+            per_ssm += R + 2 * N
+        if self.ssm_conv_bias:
+            per_ssm += di
         kinds = self.layer_kinds()
         for i, k in enumerate(kinds):
+            if self.family == "hybrid_moe":
+                n += per_attn if k == ATTN else per_ssm
+                if self.expert_layer(i):
+                    n += self.experts_per_token * per_mlp(self.d_ff) \
+                        if active_only else self.n_experts * per_mlp(self.d_ff)
+                    n += d * self.n_experts
+                else:
+                    n += per_mlp(self.d_ff)
+                continue
             if k == ATTN or self.family in ("dense", "moe", "vlm", "encdec"):
                 if k == ATTN:
                     n += per_attn
             if k == SSM:
-                di, N, R = self.d_inner, self.ssm_state, self.dt_rank
-                n += d * 2 * di + di * self.ssm_conv + di * (R + 2 * N)
-                n += R * di + di * N + 2 * di + di * d
+                n += per_ssm
                 continue
             if k == RGLRU:
                 w = self.lru_width or d

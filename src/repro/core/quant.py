@@ -110,12 +110,24 @@ def quantize(x: jax.Array, *, bits: int = 4, block: int = 128,
                    out_dtype=out_dtype, orig_shape=orig_shape)
 
 
+def nf4_lookup(idx):
+    """NF4 codebook value of each 4-bit code (0..15), as a select tree on
+    the code's bits: element-wise work, where a table gather runs as a
+    scalar gather on a TPU (and Mosaic lowers no general gather)."""
+    vals = [float(c) for c in NF4_CODE]
+    for bit in range(4):
+        hi = ((idx >> bit) & 1) == 1
+        vals = [jnp.where(hi, vals[2 * i + 1], vals[2 * i])
+                for i in range(len(vals) // 2)]
+    return vals[0]
+
+
 def dequantize(qt: QTensor, dtype=None) -> jax.Array:
     dtype = dtype or qt.out_dtype
     if qt.bits == 4:
         vals = unpack4(qt.q)
         if qt.mode == "nf4":
-            vals = jnp.take(jnp.asarray(NF4_CODE), (vals + 8).astype(jnp.int32))
+            vals = nf4_lookup((vals + 8).astype(jnp.int32))
         else:
             vals = vals.astype(jnp.float32)
     else:

@@ -144,13 +144,92 @@ def decode_attention(q, k_cache, v_cache, slot_pos):
 
 
 def selective_scan(dt, x, Bm, Cm, A):
-    """Mamba-1 recurrence: Pallas on TPU, chunked associative scan on CPU
+    """Mamba-1 recurrence from a zero state -> (y, h_last): the Pallas
+    forward and backward kernels under a custom VJP on TPU; ``None`` on
+    CPU, where the caller differentiates its chunked associative scan
     (models/ssm.py calls this from inside its shard_map body)."""
     if _use_pallas():
-        from repro.kernels import selective_scan as sk
-        return sk.selective_scan(dt, x, Bm, Cm, A,
-                                 interpret=_interpret())
-    return None  # caller falls back to its chunked associative scan
+        trace_count("ssm_scan_pallas")
+        return _ssm_scan(_interpret(), dt, x, Bm, Cm, A)
+    return None
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _ssm_scan(interpret, dt, x, Bm, Cm, A):
+    return _ssm_scan_fwd(interpret, dt, x, Bm, Cm, A)[0]
+
+
+def _ssm_scan_fwd(interpret, dt, x, Bm, Cm, A):
+    from repro.kernels import selective_scan as sk
+    y, h, hs = sk.scan_fwd(dt, x, Bm, Cm, A, interpret=interpret)
+    return (y, h), (dt, x, Bm, Cm, A, hs)
+
+
+def _ssm_scan_bwd(interpret, res, g):
+    """The residuals are the inputs and the state entering each chunk
+    (``(B, S/chunk, N, d_inner)``): the backward kernel recomputes each
+    chunk's states from it, never the (B, S, d_inner, N) tensor."""
+    from repro.kernels import selective_scan as sk
+    trace_count("ssm_scan_pallas_bwd")
+    dt, x, Bm, Cm, A, hs = res
+    grads = sk.scan_bwd(dt, x, Bm, Cm, A, hs, *g, interpret=interpret)
+    return tuple(d.astype(v.dtype) for d, v in zip(grads,
+                                                   (dt, x, Bm, Cm, A)))
+
+
+_ssm_scan.defvjp(_ssm_scan_fwd, _ssm_scan_bwd)
+
+
+def _no_grad(v):
+    """The cotangent of an input that is not trained: float0 for integers
+    and a QTensor's codes, zeros for floats and a QTensor's scales."""
+    import numpy as np
+    if isinstance(v, qlib.QTensor):
+        return dataclasses.replace(
+            v, q=np.zeros(v.q.shape, jax.dtypes.float0),
+            scales=jnp.zeros_like(v.scales))
+    if jnp.issubdtype(jnp.result_type(v), jnp.floating):
+        return jnp.zeros_like(v)
+    return np.zeros(jnp.shape(v), jax.dtypes.float0)
+
+
+def _gmm(x, w, tile_expert, *, transpose):
+    if isinstance(w, qlib.QTensor) and _use_pallas():
+        from repro.kernels import moe_gmm as gk
+        fn = gk.moe_gmm_t if transpose else gk.moe_gmm
+        return fn(x, w, tile_expert, interpret=_interpret())
+    return (ref.moe_gmm_t if transpose else ref.moe_gmm)(
+        x, w, tile_expert)
+
+
+@jax.custom_vjp
+def _moe_gmm(x, w, tile_expert):
+    return _gmm(x, w, tile_expert, transpose=False)
+
+
+def _moe_gmm_fwd(x, w, tile_expert):
+    return _moe_gmm(x, w, tile_expert), (w, tile_expert)
+
+
+def _moe_gmm_bwd(res, g):
+    w, tile_expert = res
+    pallas = isinstance(w, qlib.QTensor) and _use_pallas()
+    trace_count("moe_gmm_t_pallas" if pallas else "moe_gmm_t_ref")
+    dx = _gmm(g, w, tile_expert, transpose=True)
+    return dx, _no_grad(w), _no_grad(tile_expert)
+
+
+_moe_gmm.defvjp(_moe_gmm_fwd, _moe_gmm_bwd)
+
+
+def moe_gmm(x, w, tile_expert):
+    """Grouped matmul of expert-sorted rows (``kernels.moe_gmm``): for a
+    quantized ``w`` the Pallas kernel and its transposed twin for ``dx``
+    on TPU, the per-expert jnp oracle elsewhere (and for a dense ``w``).
+    The experts are frozen: no ``dW``."""
+    pallas = isinstance(w, qlib.QTensor) and _use_pallas()
+    trace_count("moe_gmm_pallas" if pallas else "moe_gmm_ref")
+    return _moe_gmm(x, w, tile_expert)
 
 
 def quant_matmul(x, qt: qlib.QTensor):

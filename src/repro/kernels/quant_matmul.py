@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.quant import NF4_CODE, QTensor
+from repro.core.quant import QTensor, nf4_lookup
 
 LANES = 128
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -44,17 +44,6 @@ def pad_groups(qt: QTensor, gk: int, n_pad: int):
         return qt.q, qt.scales
     pad = ((0, g_pad), (0, 0), (0, n_pad))
     return jnp.pad(qt.q, pad), jnp.pad(qt.scales, pad)
-
-
-def nf4_lookup(idx):
-    """NF4 codebook value of each 4-bit code, as a select tree on the
-    code's bits (Mosaic lowers no general gather)."""
-    vals = [float(c) for c in NF4_CODE]
-    for bit in range(4):
-        hi = ((idx >> bit) & 1) == 1
-        vals = [jnp.where(hi, vals[2 * i + 1], vals[2 * i])
-                for i in range(len(vals) // 2)]
-    return vals[0]
 
 
 def dequant_tile(q_ref, s_ref, *, bits, mode):

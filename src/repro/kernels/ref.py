@@ -221,3 +221,32 @@ def blockwise_quant(x: jax.Array, *, bits: int = 8, block: int = 128,
     qt = qlib.quantize(jnp.pad(x, pad), bits=bits, block=block,
                        mode=mode)
     return dataclasses.replace(qt, orig_shape=tuple(x.shape))
+
+
+# ------------------------------------------------------------------
+# grouped matmul over the experts of a dropless MoE
+# ------------------------------------------------------------------
+def _gmm_rows(x, qt: qlib.QTensor, tile_expert, transpose):
+    R = x.shape[0]
+    tm = R // tile_expert.shape[0]
+    row_e = jnp.repeat(tile_expert, tm)
+    w = qlib.maybe_dequantize(qt, jnp.float32).astype(jnp.float32)
+    xf = x.astype(jnp.float32)
+    y = 0.0
+    for e in range(w.shape[0]):
+        we = w[e].T if transpose else w[e]
+        y = y + jnp.where((row_e == e)[:, None], xf @ we, 0.0)
+    return y.astype(x.dtype)
+
+
+def moe_gmm(x, qt: qlib.QTensor, tile_expert):
+    """``x (R, K)`` rows grouped by expert in tiles of ``R /
+    len(tile_expert)`` rows, tile ``i`` of expert ``tile_expert[i]``:
+    each row times its expert's dequantized ``(K, N)`` weight, f32
+    accumulation — the oracle and CPU path of ``kernels.moe_gmm``."""
+    return _gmm_rows(x, qt, tile_expert, False)
+
+
+def moe_gmm_t(g, qt: qlib.QTensor, tile_expert):
+    """``g (R, N)`` times each row's expert's weight transposed."""
+    return _gmm_rows(g, qt, tile_expert, True)

@@ -16,10 +16,13 @@ are trainable — exactly TriplePlay's client-side configuration.
 Layers are stacked and ``lax.scan``ned (hybrid RG-LRU/attention patterns
 use a per-layer flag + ``lax.cond``) so HLO size and compile time are O(1)
 in depth. ``cfg.first_k_dense`` layers (kimi-k2) are unrolled before the
-scanned MoE stack.
+scanned MoE stack. The ``hybrid_moe`` family (Jamba) stacks over periods:
+each position of a period holds its own parameter tree (Mamba or
+attention, experts or a dense SwiGLU), scanned over the periods.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Dict, Optional
 
@@ -32,7 +35,7 @@ from repro.configs.base import ATTN, RGLRU, SSM, InputShape, ModelConfig
 from repro.core import adapter as adapter_lib
 from repro.core import losses, optim
 from repro.core import lora as lora_lib
-from repro.core.quant import quantize_tree, quantize_tree_specs
+from repro.core.quant import QTensor, quantize_tree, quantize_tree_specs
 from repro.models import layers as L
 from repro.models import moe as moe_lib
 from repro.models import rglru as rglru_lib
@@ -59,10 +62,28 @@ def _seq_axis(cfg, S):
 
 
 # ================================================================ params
-def _lora_targets(cfg: ModelConfig) -> Dict[str, tuple]:
+def positions(cfg: ModelConfig):
+    """(mixer, feed-forward) of each position of one period of a
+    ``hybrid_moe`` stack: the mixer from ``attn_pattern``, the
+    feed-forward ``"moe"`` on expert layers and ``"mlp"`` elsewhere."""
+    pat = cfg.attn_pattern
+    return tuple((pat[j % len(pat)], "moe" if cfg.expert_layer(j) else "mlp")
+                 for j in range(cfg.period))
+
+
+def _lora_targets(cfg: ModelConfig, position=None) -> Dict[str, tuple]:
     d, qd, kvd, ff = cfg.d_model, cfg.q_dim, cfg.kv_dim, cfg.d_ff
     t: Dict[str, tuple] = {}
     fam = cfg.family
+    if position is not None:      # hybrid_moe: experts and router frozen
+        mixer, ffn = position
+        if mixer == ATTN:
+            t.update(wq=(d, qd), wk=(d, kvd), wv=(d, kvd), wo=(qd, d))
+        else:
+            t.update(in_proj_x=(d, cfg.d_inner), out_proj=(cfg.d_inner, d))
+        if ffn == "mlp":
+            t.update(wg=(d, ff), wu=(d, ff), wd=(ff, d))
+        return t
     if fam != "ssm":
         t.update(wq=(d, qd), wk=(d, kvd), wv=(d, kvd), wo=(qd, d))
     if fam in ("dense", "vlm", "encdec"):
@@ -79,8 +100,8 @@ def _lora_targets(cfg: ModelConfig) -> Dict[str, tuple]:
     return t
 
 
-def _init_lora_layer(cfg, rng):
-    t = _lora_targets(cfg)
+def _init_lora_layer(cfg, rng, position=None):
+    t = _lora_targets(cfg, position)
     ks = jax.random.split(rng, len(t))
     tdt = jnp.dtype(cfg.trainable_dtype)
     return {n: lora_lib.init_pair(k, kk, nn, cfg.lora_rank, dtype=tdt)
@@ -166,6 +187,42 @@ def _layer_specs(cfg: ModelConfig, dtype, lead=(), *, dense_ff: int = 0,
     return p
 
 
+def _restacked(tree):
+    """QTensors made one layer (or expert) at a time and stacked by
+    ``lax.map`` carry the unstacked shape; give them the stacked one."""
+    def fix(l):
+        if not isinstance(l, QTensor):
+            return l
+        *lead, G, _, N = l.q.shape
+        return dataclasses.replace(l, orig_shape=(*lead, G * l.block, N))
+    return jax.tree.map(fix, tree, is_leaf=lambda l: isinstance(l, QTensor))
+
+
+def _init_position(cfg: ModelConfig, rng, dtype, position):
+    """One layer of a ``hybrid_moe`` period, its frozen weights made and
+    quantized here (the experts one at a time), so a stack of them never
+    exists in bf16."""
+    mixer, ffn = position
+    ks = jax.random.split(rng, 8)
+    d = cfg.d_model
+    quant = (lambda t: quantize_tree(t, bits=cfg.quant_bits,
+                                     block=cfg.quant_block,
+                                     mode=cfg.quant_mode)) \
+        if cfg.quant_bits else (lambda t: t)
+    p: Dict[str, Any] = {"ln1": jnp.zeros((d,), jnp.float32),
+                         "ln2": jnp.zeros((d,), jnp.float32)}
+    if mixer == ATTN:
+        p["attn"] = L.init_attention(ks[0], cfg, dtype)
+    else:
+        p["mamba"] = ssm_lib.init_mamba(ks[0], cfg, dtype)
+    if ffn == "moe":
+        p["moe"] = _restacked(moe_lib.init_experts(ks[4], cfg, dtype,
+                                                   quantize=quant))
+    else:
+        p["mlp"] = L.init_mlp(ks[1], d, cfg.d_ff, "swiglu", dtype)
+    return quant(p)
+
+
 class Model:
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
@@ -174,6 +231,10 @@ class Model:
             [KIND_ID[k] for k in cfg.layer_kinds()[cfg.first_k_dense:]],
             np.int32)
         self.hybrid = cfg.family == "hybrid"
+        # hybrid_moe: parameters stacked over periods, each position of a
+        # period with its own tree (mixer and feed-forward kinds differ)
+        self.positions = positions(cfg) if cfg.family == "hybrid_moe" \
+            else None
 
     # ---------------------------------------------------------- params
     def init_params(self, rng):
@@ -189,9 +250,11 @@ class Model:
             jnp.sqrt(jnp.asarray(cfg.d_model, dt)),
             "final_norm": jnp.zeros((cfg.d_model,), jnp.float32),
         }
-        if not cfg.use_rope:
+        if not cfg.use_rope and cfg.max_pos:
             frozen["pos_embed"] = jax.random.normal(
                 k_pos, (cfg.max_pos, cfg.d_model), dt) * 0.02
+        if self.positions is not None:
+            return self._init_periods(frozen, k_lay, k_lora, k_ad)
         frozen["layers"] = jax.vmap(
             lambda k: _init_layer(cfg, k, dt))(
                 jax.random.split(k_lay, self.n_scanned))
@@ -233,16 +296,40 @@ class Model:
                                      cfg.encoder_layers))
         return {"frozen": frozen, "trainable": trainable}
 
+    def _init_periods(self, frozen, k_lay, k_lora, k_ad):
+        """Layer ``i = period·p + j`` takes key ``i`` of ``k_lay``'s (and
+        ``k_lora``'s) split; position ``j``'s trees stack over ``p``,
+        made one layer at a time (``lax.map``)."""
+        cfg = self.cfg
+        dt = _dtype(cfg)
+        P = len(self.positions)
+        k_layers = jax.random.split(k_lay, cfg.n_layers)
+        k_loras = jax.random.split(k_lora, cfg.n_layers)
+        frozen["layers"] = [_restacked(jax.lax.map(
+            lambda k, pos=pos: _init_position(cfg, k, dt, pos),
+            k_layers[j::P])) for j, pos in enumerate(self.positions)]
+        tdt = jnp.dtype(cfg.trainable_dtype)
+        trainable = {
+            "lora": [jax.vmap(lambda k, pos=pos: _init_lora_layer(
+                cfg, k, pos))(k_loras[j::P])
+                for j, pos in enumerate(self.positions)],
+            "adapter": adapter_lib.init(
+                k_ad, cfg.d_model, n_heads=cfg.adapter_heads,
+                d_ff=cfg.adapter_d_ff, dtype=tdt)}
+        return {"frozen": frozen, "trainable": trainable}
+
     def param_specs(self):
         cfg = self.cfg
         dt = _dtype(cfg)
+        if self.positions is not None:
+            return jax.eval_shape(self.init_params, jax.random.PRNGKey(0))
         S = lambda *sh, d=dt: jax.ShapeDtypeStruct(sh, d)
         frozen: Dict[str, Any] = {
             "embed": S(cfg.vocab_size, cfg.d_model),
             "head": S(cfg.d_model, cfg.vocab_size),
             "final_norm": S(cfg.d_model, d=jnp.float32),
         }
-        if not cfg.use_rope:
+        if not cfg.use_rope and cfg.max_pos:
             frozen["pos_embed"] = S(cfg.max_pos, cfg.d_model)
         frozen["layers"] = _layer_specs(cfg, dt, lead=(self.n_scanned,))
         if cfg.first_k_dense:
@@ -421,7 +508,7 @@ class Model:
             positions = None
         else:
             positions = jnp.arange(S)
-            if not cfg.use_rope:
+            if not cfg.use_rope and cfg.max_pos:
                 x = x + jnp.take(frozen["pos_embed"],
                                  jnp.minimum(positions, cfg.max_pos - 1),
                                  axis=0)[None]
@@ -492,15 +579,78 @@ class Model:
                         lambda l: l[None], new_dense_cache[0])
         return x, aux, new_cache
 
+    # ---------------------------------------------------------- periods
+    def _position_block(self, j, p, lo, x, positions):
+        """Position ``j`` of a ``hybrid_moe`` period (training shapes).
+        Returns (x, aux, the largest expert load, pad rows)."""
+        cfg = self.cfg
+        mixer, ffn = self.positions[j]
+        xin = L.rms_norm(x, p["ln1"])
+        if mixer == ATTN:
+            with jax.named_scope("attn"):
+                h, _ = L.attention(p["attn"], xin, positions, cfg, lora=lo,
+                                   causal=True, window=cfg.window,
+                                   use_rope=cfg.use_rope)
+        else:
+            with jax.named_scope("mamba"):
+                h, _ = ssm_lib.mamba_block(p["mamba"], xin, cfg, lora=lo)
+        x = x + h
+        xin = L.rms_norm(x, p["ln2"])
+        if ffn == "moe":
+            with jax.named_scope("moe"):
+                y, aux, st = moe_lib.moe_dropless(
+                    p["moe"], xin.reshape(-1, cfg.d_model), cfg)
+            return (x + y.reshape(x.shape), aux, st["moe_load_max"],
+                    st["moe_pad_rows"])
+        with jax.named_scope("mlp"):
+            y = L.mlp(p["mlp"], xin, cfg, lora=lo, kind="swiglu")
+        zero = jnp.zeros((), jnp.float32)
+        return x + y, zero, zero, zero
+
+    def _period_stack(self, frozen, trainable, x, positions):
+        """``lax.scan`` over periods; inside, each position is its own
+        layer with its own remat (``nothing_saveable``), so the backward
+        keeps one layer's residuals live at a time. Returns (x, aux,
+        {moe_load_max, moe_pad_rows})."""
+        def layer(j):
+            f = lambda p, lo, x: self._position_block(j, p, lo, x, positions)
+            return jax.checkpoint(
+                f, policy=jax.checkpoint_policies.nothing_saveable) \
+                if self.cfg.remat else f
+
+        fns = [layer(j) for j in range(len(self.positions))]
+
+        def body(carry, inp):
+            x, aux, load, pad = carry
+            ps, los = inp
+            for f, p, lo in zip(fns, ps, los):
+                x, a, ld, pd = f(p, lo, x)
+                aux, load, pad = aux + a, jnp.maximum(load, ld), pad + pd
+            return (x, aux, load, pad), None
+
+        z = jnp.zeros((), jnp.float32)
+        (x, aux, load, pad), _ = lax.scan(
+            body, (x, z, z, z), (frozen["layers"], trainable["lora"]))
+        return x, aux, {"moe_load_max": load, "moe_pad_rows": pad}
+
     def forward(self, frozen, trainable, batch):
         """Training-shape forward. Returns (logits, moe aux loss)."""
+        logits, aux, _ = self._forward(frozen, trainable, batch)
+        return logits, aux
+
+    def _forward(self, frozen, trainable, batch):
         cfg = self.cfg
         enc_out = None
         if cfg.family == "encdec":
             enc_out = self._encode(frozen, trainable, batch["frames"])
         x, positions = self._embed_inputs(frozen, batch, "train")
-        x, aux, _ = self._stack(frozen, trainable, x, positions, enc_out,
-                                "train")
+        stats = {}
+        if self.positions is not None:
+            x, aux, stats = self._period_stack(frozen, trainable, x,
+                                               positions)
+        else:
+            x, aux, _ = self._stack(frozen, trainable, x, positions,
+                                    enc_out, "train")
         x = L.rms_norm(x, frozen["final_norm"])
         x = adapter_lib.apply(trainable["adapter"], x,
                               n_heads=cfg.adapter_heads, causal=True)
@@ -511,7 +661,7 @@ class Model:
         # full (B,S,V) logits — measured 16 GiB/device on yi-9b train_4k)
         logits = rt_lib.constrain(logits, _dp(cfg),
                                   _seq_axis(cfg, logits.shape[1]), None)
-        return logits, aux
+        return logits, aux, stats
 
     # ---------------------------------------------------------- training
     @jax.named_scope("forward")
@@ -519,11 +669,12 @@ class Model:
         """Its ops sit in the ``forward`` scope: under ``value_and_grad``
         the forward's op names carry ``jvp(forward)``, the backward's
         ``transpose(jvp(forward))``, a remat recompute's
-        ``rematted_computation``."""
-        logits, aux = self.forward(frozen, trainable, batch)
+        ``rematted_computation``. A dropless MoE adds its routing
+        statistics to the parts (``moe_load_max``, ``moe_pad_rows``)."""
+        logits, aux, stats = self._forward(frozen, trainable, batch)
         mask = batch.get("mask")
         ce = losses.cross_entropy(logits, batch["labels"], mask)
-        return ce + 0.01 * aux, {"ce": ce, "aux": aux}
+        return ce + 0.01 * aux, {"ce": ce, "aux": aux, **stats}
 
     def train_step(self, frozen, trainable, opt_state, batch, *,
                    lr=1e-4):
@@ -558,6 +709,12 @@ class Model:
         return trainable, opt_state, metrics
 
     # ---------------------------------------------------------- serving
+    def _trains_only(self):
+        if self.positions is not None:
+            raise NotImplementedError(
+                f"{self.cfg.family}: the period stack has no prefill or "
+                "decode path yet; it trains (Model.train_step)")
+
     def effective_cache_len(self, context_len: int) -> int:
         if self.cfg.window:
             return min(context_len, self.cfg.window)
@@ -570,6 +727,7 @@ class Model:
         pass the serving context length so subsequent ``decode_step`` calls
         have room (sliding-window archs cap at the window regardless)."""
         cfg = self.cfg
+        self._trains_only()
         enc_out = None
         if cfg.family == "encdec":
             enc_out = self._encode(frozen, trainable, batch["frames"])
@@ -590,9 +748,10 @@ class Model:
     def decode_step(self, frozen, trainable, cache, tokens, pos):
         """tokens: (B, 1); pos: scalar int32. Returns (logits (B, V), cache)."""
         cfg = self.cfg
+        self._trains_only()
         dt = _dtype(cfg)
         x = jnp.take(frozen["embed"], tokens, axis=0).astype(dt)
-        if not cfg.use_rope:
+        if not cfg.use_rope and cfg.max_pos:
             x = x + jnp.take(frozen["pos_embed"],
                              jnp.minimum(pos, cfg.max_pos - 1),
                              axis=0)[None, None]

@@ -35,16 +35,31 @@ from repro.models import runtime as rt_lib
 
 
 # ------------------------------------------------------------------ params
-def init_experts(rng, cfg: ModelConfig, dtype):
+def init_experts(rng, cfg: ModelConfig, dtype, quantize=None):
+    """Router and experts. ``quantize`` (a function of one expert's
+    ``{wg, wu, wd}``) makes and quantizes the experts one at a time, so
+    no bf16 copy of the whole layer's experts ever exists."""
     E, d, ff = cfg.n_experts, cfg.d_model, cfg.d_ff
     ks = jax.random.split(rng, 4)
     s = lambda fan: 1.0 / jnp.sqrt(fan)
-    return {
-        "router": jax.random.normal(ks[0], (d, E), jnp.float32) * s(d),
-        "wg": jax.random.normal(ks[1], (E, d, ff), dtype) * s(d),
-        "wu": jax.random.normal(ks[2], (E, d, ff), dtype) * s(d),
-        "wd": jax.random.normal(ks[3], (E, ff, d), dtype) * s(ff),
-    }
+    router = jax.random.normal(ks[0], (d, E), jnp.float32) * s(d)
+    if quantize is None:
+        return {
+            "router": router,
+            "wg": jax.random.normal(ks[1], (E, d, ff), dtype) * s(d),
+            "wu": jax.random.normal(ks[2], (E, d, ff), dtype) * s(d),
+            "wd": jax.random.normal(ks[3], (E, ff, d), dtype) * s(ff),
+        }
+
+    def one(k):
+        kg, ku, kd = jax.random.split(k, 3)
+        return quantize({
+            "wg": jax.random.normal(kg, (d, ff), dtype) * s(d),
+            "wu": jax.random.normal(ku, (d, ff), dtype) * s(d),
+            "wd": jax.random.normal(kd, (ff, d), dtype) * s(ff)})
+
+    experts = lax.map(one, jax.random.split(ks[1], E))
+    return {"router": router, **experts}
 
 
 def expert_specs(cfg: ModelConfig, dtype, lead=()):
@@ -80,11 +95,14 @@ def _tree_map_with_name(fn, tree):
 
 # ------------------------------------------------------------------ routing
 def _route(router_w, x2d, cfg: ModelConfig):
-    """x2d: (T, d) -> (gates (T,k), ids (T,k)) with renormalized gates."""
+    """x2d: (T, d) -> (gates (T,k), ids (T,k), aux): the top-k of a
+    softmax over all experts, the gates renormalized to sum to one where
+    ``cfg.router_renorm``."""
     logits = x2d.astype(jnp.float32) @ router_w.astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, ids = lax.top_k(probs, cfg.experts_per_token)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if cfg.router_renorm:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     # auxiliary load-balance statistics (Switch-style)
     density = jnp.mean(jax.nn.one_hot(ids[:, 0], cfg.n_experts), axis=0)
     p_mean = jnp.mean(probs, axis=0)
@@ -136,6 +154,70 @@ def _moe_local(p, x2d, cfg: ModelConfig):
     y = (y_copies.reshape(T, k, d) *
          gates[..., None].astype(y_copies.dtype)).sum(axis=1)
     return y, aux
+
+
+# ------------------------------------------------------------------ dropless
+GROUP_ROWS = 256      # row tile of the grouped matmul (kernels/moe_gmm.py)
+
+
+def group_by_expert(ids_flat: jax.Array, E: int, tm: int = GROUP_ROWS):
+    """Dropless grouping of the ``n`` token copies by expert: sorted
+    stably by expert, each expert's group padded to whole ``tm``-row
+    tiles. Returns ``(order, dest, tile_expert, n_tiles, counts)``: the
+    sorted copies' source positions, each one's row in the grouped
+    buffer of the static ``round_up(n + E·(tm − 1), tm)`` rows, the
+    expert of each row tile, the tiles in use and each expert's
+    copies."""
+    n = ids_flat.shape[0]
+    order = jnp.argsort(ids_flat, stable=True)
+    sorted_ids = ids_flat[order]
+    counts = jnp.zeros((E,), jnp.int32).at[ids_flat].add(1)
+    padded = -(-counts // tm) * tm
+    ends = jnp.cumsum(padded)
+    first = jnp.cumsum(counts) - counts            # in sorted order
+    dest = (ends - padded)[sorted_ids] + \
+        jnp.arange(n, dtype=jnp.int32) - first[sorted_ids]
+    rows = -(-(n + E * (tm - 1)) // tm) * tm
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        ends, jnp.arange(0, rows, tm), side="right"), E - 1)
+    return order, dest, tile_expert.astype(jnp.int32), ends[-1] // tm, \
+        counts
+
+
+def moe_dropless(p, x2d, cfg: ModelConfig, tm: int = GROUP_ROWS):
+    """Every token copy through its expert, none dropped: route, group
+    the copies by expert (:func:`group_by_expert`), run the experts'
+    SwiGLU through the grouped NF4 matmul (``kernels.ops.moe_gmm``), and
+    combine by a gate-weighted scatter-add of the copies' rows (pad rows
+    are never read). x2d: (T, d) -> (y (T, d), aux, stats) with stats
+    ``moe_load_max`` (the fullest expert's copies over the mean) and
+    ``moe_pad_rows`` (pad rows inside the expert groups). The grouped
+    matmul computes every row of the static buffer, so its work does not
+    follow the routing."""
+    from repro.kernels import ops as kops
+    T, d = x2d.shape
+    E, k = cfg.n_experts, cfg.experts_per_token
+    with jax.named_scope("router"):
+        gates, ids, aux = _route(p["router"], x2d, cfg)
+    with jax.named_scope("dispatch"):
+        order, dest, tile_expert, n_tiles, counts = group_by_expert(
+            ids.reshape(-1), E, tm)
+        src = order // k
+        xs = jnp.zeros((tile_expert.shape[0] * tm, d), x2d.dtype).at[
+            dest].set(x2d[src])
+    with jax.named_scope("experts"):
+        gmm = lambda a, w: kops.moe_gmm(a, w, tile_expert)
+        h = jax.nn.silu(gmm(xs, p["wg"])) * gmm(xs, p["wu"])
+        ys = gmm(h, p["wd"])
+    with jax.named_scope("combine"):
+        w = gates.reshape(-1)[order][:, None]
+        y = jnp.zeros((T, d), jnp.float32).at[src].add(
+            ys[dest].astype(jnp.float32) * w).astype(x2d.dtype)
+    stats = {"moe_load_max": counts.max() * (E / (T * k)),
+             "moe_pad_rows": n_tiles * tm - T * k}
+    stats = {n: lax.stop_gradient(v.astype(jnp.float32))
+             for n, v in stats.items()}
+    return y, aux, stats
 
 
 # ------------------------------------------------------------------ dist

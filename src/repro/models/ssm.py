@@ -28,6 +28,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core import lora as lora_lib
+from repro.models import layers as L
 from repro.models import runtime as rt_lib
 
 
@@ -71,7 +72,9 @@ def _chunked_ssm_scan(dt, A, Bm, Cm, xc, h0, chunk: int):
     (B, chunk, di, N) state tensor never materializes beyond one chunk.
 
     dt, xc: (B,S,di); A: (di,N); Bm, Cm: (B,S,N); h0: (B,di,N) f32.
-    Returns (y (B,S,di) f32, h_last)."""
+    Returns (y (B,S,di) f32, h_last). Padded steps have Δ = 0, so a = 1
+    and b = 0: the state passes through them and h_last is the last
+    real step's."""
     B, S, di = xc.shape
     N = A.shape[-1]
     chunk = min(chunk, S)
@@ -96,10 +99,6 @@ def _chunked_ssm_scan(dt, A, Bm, Cm, xc, h0, chunk: int):
 
     h_last, y = lax.scan(step, h0, (dt_c, xc_c, B_c, C_c))
     y = jnp.moveaxis(y, 0, 1).reshape(B, Sp, di)[:, :S]
-    if pad:
-        # padded steps have a=exp(0·A)=1, b=0 -> state frozen; h_last is
-        # correct only when pad == 0, so recompute from the last valid row
-        pass
     return y, h_last
 
 
@@ -109,7 +108,7 @@ def init_mamba(rng, cfg: ModelConfig, dtype):
                       cfg.ssm_conv)
     ks = jax.random.split(rng, 6)
     s = lambda fan: 1.0 / jnp.sqrt(fan)
-    return {
+    p = {
         "in_proj_x": jax.random.normal(ks[0], (d, di), dtype) * s(d),
         "in_proj_z": jax.random.normal(ks[5], (d, di), dtype) * s(d),
         "conv_w": jax.random.normal(ks[1], (K, di), dtype) * s(K),
@@ -121,33 +120,53 @@ def init_mamba(rng, cfg: ModelConfig, dtype):
         "d_skip": jnp.ones((di,), jnp.float32),
         "out_proj": jax.random.normal(ks[4], (di, d), dtype) * s(di),
     }
+    if cfg.ssm_conv_bias:
+        p["conv_b"] = jax.random.normal(jax.random.fold_in(rng, 1), (di,),
+                                        jnp.float32) * s(K)
+    if cfg.ssm_inner_norms:
+        p.update(dt_norm=jnp.zeros((R,), jnp.float32),
+                 b_norm=jnp.zeros((N,), jnp.float32),
+                 c_norm=jnp.zeros((N,), jnp.float32))
+    return p
 
 
 def mamba_specs(cfg: ModelConfig, dtype, lead=()):
     d, di, N, R, K = (cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.dt_rank,
                       cfg.ssm_conv)
     f = lambda *sh, dt=dtype: jax.ShapeDtypeStruct((*lead, *sh), dt)
-    return {"in_proj_x": f(d, di), "in_proj_z": f(d, di),
-            "conv_w": f(K, di),
-            "x_proj": f(di, R + 2 * N), "dt_proj": f(R, di),
-            "dt_bias": f(di, dt=jnp.float32),
-            "a_log": f(di, N, dt=jnp.float32),
-            "d_skip": f(di, dt=jnp.float32), "out_proj": f(di, d)}
+    p = {"in_proj_x": f(d, di), "in_proj_z": f(d, di),
+         "conv_w": f(K, di),
+         "x_proj": f(di, R + 2 * N), "dt_proj": f(R, di),
+         "dt_bias": f(di, dt=jnp.float32),
+         "a_log": f(di, N, dt=jnp.float32),
+         "d_skip": f(di, dt=jnp.float32), "out_proj": f(di, d)}
+    if cfg.ssm_conv_bias:
+        p["conv_b"] = f(di, dt=jnp.float32)
+    if cfg.ssm_inner_norms:
+        p.update(dt_norm=f(R, dt=jnp.float32), b_norm=f(N, dt=jnp.float32),
+                 c_norm=f(N, dt=jnp.float32))
+    return p
 
 
 def mamba_partition_specs(cfg: ModelConfig, tp_axis="model", lead=()):
     """Per-leaf PartitionSpecs: the d_inner dim -> tp axis. Shared by the
     launch sharding rules and the shard_map in_specs (they must agree)."""
     nl = (None,) * len(lead)
-    return {"in_proj_x": P(*nl, None, tp_axis),
-            "in_proj_z": P(*nl, None, tp_axis),
-            "conv_w": P(*nl, None, tp_axis),
-            "x_proj": P(*nl, tp_axis, None),
-            "dt_proj": P(*nl, None, tp_axis),
-            "dt_bias": P(*nl, tp_axis),
-            "a_log": P(*nl, tp_axis, None),
-            "d_skip": P(*nl, tp_axis),
-            "out_proj": P(*nl, tp_axis, None)}
+    p = {"in_proj_x": P(*nl, None, tp_axis),
+         "in_proj_z": P(*nl, None, tp_axis),
+         "conv_w": P(*nl, None, tp_axis),
+         "x_proj": P(*nl, tp_axis, None),
+         "dt_proj": P(*nl, None, tp_axis),
+         "dt_bias": P(*nl, tp_axis),
+         "a_log": P(*nl, tp_axis, None),
+         "d_skip": P(*nl, tp_axis),
+         "out_proj": P(*nl, tp_axis, None)}
+    if cfg.ssm_conv_bias:
+        p["conv_b"] = P(*nl, tp_axis)
+    if cfg.ssm_inner_norms:      # over dt_rank and N: replicated
+        p.update(dt_norm=P(*nl, None), b_norm=P(*nl, None),
+                 c_norm=P(*nl, None))
+    return p
 
 
 def mamba_cache_init(cfg: ModelConfig, batch: int, dtype):
@@ -163,15 +182,28 @@ def mamba_cache_specs(cfg: ModelConfig, batch: int, dtype, lead=()):
 
 
 # ---------------------------------------------------------------- forward
-def _causal_conv(conv_w, x1, dtype):
-    """Depthwise causal conv over S. x1: (B, S, di)."""
+def _causal_conv(conv_w, x1, dtype, bias=None):
+    """Depthwise causal conv over S, plus ``bias`` where the config has
+    one. x1: (B, S, di)."""
     K = conv_w.shape[0]
     w = conv_w.astype(dtype)[:, None, :]
     x_pad = jnp.pad(x1, ((0, 0), (K - 1, 0), (0, 0)))
-    return lax.conv_general_dilated(
+    y = lax.conv_general_dilated(
         x_pad, w, window_strides=(1,), padding="VALID",
         dimension_numbers=("NHC", "HIO", "NHC"),
         feature_group_count=x1.shape[-1])
+    return y if bias is None else y + bias.astype(dtype)
+
+
+def _dt_b_c(p, proj, R, N):
+    """Split x_proj's output into Δ's rank-R input, B and C, with Jamba's
+    RMSNorms on each where the config has them."""
+    dt_r, Bm, Cm = jnp.split(proj, [R, R + N], axis=-1)
+    if "dt_norm" in p:
+        dt_r = L.rms_norm(dt_r, p["dt_norm"])
+        Bm = L.rms_norm(Bm, p["b_norm"])
+        Cm = L.rms_norm(Cm, p["c_norm"])
+    return dt_r, Bm, Cm
 
 
 def _lora_delta(x, pair, sl, alpha, rank):
@@ -199,12 +231,12 @@ def _mamba_core(p, x, cfg: ModelConfig, h0, lo, *, shard=None):
     x1 = x @ p["in_proj_x"].astype(dtype) + _lora_delta(
         x, lo.get("in_proj_x"), sl_x, alpha, rank)
     z = x @ p["in_proj_z"].astype(dtype)
-    xc = jax.nn.silu(_causal_conv(p["conv_w"], x1, dtype))
+    xc = jax.nn.silu(_causal_conv(p["conv_w"], x1, dtype, p.get("conv_b")))
 
     proj = (xc @ p["x_proj"].astype(dtype)).astype(jnp.float32)
     if shard is not None:
         proj = lax.psum(proj, rt_lib.get_runtime().tp_axis)
-    dt_r, Bm, Cm = jnp.split(proj, [R, R + N], axis=-1)
+    dt_r, Bm, Cm = _dt_b_c(p, proj, R, N)
     dt = jax.nn.softplus(dt_r @ p["dt_proj"].astype(jnp.float32) +
                          p["dt_bias"])
     A = -jnp.exp(p["a_log"])
@@ -307,10 +339,13 @@ def mamba_decode(p, x, cache, cfg: ModelConfig, *, lora=None):
     window = jnp.concatenate([cache["conv"],
                               x1[:, None, :].astype(cache["conv"].dtype)], 1)
     w = p["conv_w"].astype(dtype)
-    xc = jax.nn.silu(jnp.einsum("bkd,kd->bd", window.astype(dtype), w))
+    xc = jnp.einsum("bkd,kd->bd", window.astype(dtype), w)
+    if "conv_b" in p:
+        xc = xc + p["conv_b"].astype(dtype)
+    xc = jax.nn.silu(xc)
     N, R = cfg.ssm_state, cfg.dt_rank
     proj = (xc @ p["x_proj"].astype(dtype)).astype(jnp.float32)
-    dt_r, Bm, Cm = jnp.split(proj, [R, R + N], axis=-1)
+    dt_r, Bm, Cm = _dt_b_c(p, proj, R, N)
     dt = jax.nn.softplus(dt_r @ p["dt_proj"].astype(jnp.float32) +
                          p["dt_bias"])
     A = -jnp.exp(p["a_log"])

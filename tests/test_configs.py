@@ -18,13 +18,18 @@ EXPECTED = {
 }
 
 
+# registered besides the assigned ten: the hybrid SSM/MoE client
+REGISTERED = {**EXPECTED,
+              "jamba2-mini": ("hybrid_moe", 32, 4096, 32, 8, 14336, 65536)}
+
+
 def test_all_ten_assigned():
     assert set(ARCHS) == set(EXPECTED)
 
 
-@pytest.mark.parametrize("arch", sorted(EXPECTED))
+@pytest.mark.parametrize("arch", sorted(REGISTERED))
 def test_exact_table(arch):
-    fam, L, d, H, kv, ff, V = EXPECTED[arch]
+    fam, L, d, H, kv, ff, V = REGISTERED[arch]
     c = get_config(arch)
     assert (c.family, c.n_layers, c.d_model, c.n_heads, c.n_kv_heads,
             c.d_ff, c.vocab_size) == (fam, L, d, H, kv, ff, V)
@@ -85,3 +90,27 @@ def test_reduced_is_same_family(arch):
     assert r.family == c.family
     assert r.attn_pattern == c.attn_pattern
     assert (r.window is None) == (c.window is None)
+
+
+def test_jamba_pattern_and_param_count():
+    """Jamba2-Mini (config.json): attention iff i % 8 == 4, experts iff
+    i % 2 == 1, 16 experts top-2, Mamba d_state 16 / d_conv 4 / expand 2
+    / dt_rank 256; 51.5 B parameters (52B-A12B as published)."""
+    c = get_config("jamba2-mini")
+    kinds = c.layer_kinds()
+    assert [i for i, k in enumerate(kinds) if k == "attn"] == \
+        [4, 12, 20, 28]
+    assert [i for i in range(32) if c.expert_layer(i)] == \
+        list(range(1, 32, 2))
+    assert (c.n_experts, c.experts_per_token, c.n_shared_experts) == \
+        (16, 2, 0)
+    assert (c.ssm_state, c.ssm_conv, c.d_inner, c.dt_rank) == \
+        (16, 4, 8192, 256)
+    assert not c.router_renorm
+    assert not c.use_rope and c.max_pos == 0
+    assert c.period == 8
+    assert abs(c.param_count() / 51.5e9 - 1) < 0.02
+    assert 11.5e9 < c.param_count(active_only=True) < 12.5e9
+    r = get_reduced("jamba2-mini")
+    assert r.family == c.family and r.n_layers == c.period
+    assert r.attn_pattern == c.attn_pattern

@@ -165,22 +165,111 @@ def test_decode_attention_matches_flash_last_token(rng):
                                np.asarray(full[:, -1]), atol=1e-5)
 
 
-@pytest.mark.parametrize("B,S,di,N,bd,ch", [
-    (2, 64, 32, 8, 16, 16),
-    (1, 50, 16, 4, 16, 32),    # non-multiple S (padding path)
-    (2, 96, 64, 16, 32, 48),
-])
-def test_selective_scan_vs_ref(B, S, di, N, bd, ch, rng):
+def _scan_inputs(rng, B, S, di, N):
     dt = jnp.asarray(np.abs(rng.randn(B, S, di)) * 0.1, jnp.float32)
     x = jnp.asarray(rng.randn(B, S, di), jnp.float32)
     Bm = jnp.asarray(rng.randn(B, S, N), jnp.float32)
     Cm = jnp.asarray(rng.randn(B, S, N), jnp.float32)
     A = jnp.asarray(-np.abs(rng.randn(di, N)), jnp.float32)
-    from repro.kernels.selective_scan import selective_scan as ssk
+    return dt, x, Bm, Cm, A
+
+
+@pytest.mark.parametrize("B,S,di,N,ch", [
+    (2, 64, 32, 8, 16),
+    (1, 50, 16, 4, 32),        # non-multiple S (padding path)
+    (2, 96, 64, 16, 48),
+    (1, 40, 256, 16, 16),      # two lane blocks of d_inner
+])
+def test_selective_scan_vs_ref(B, S, di, N, ch, rng):
+    dt, x, Bm, Cm, A = _scan_inputs(rng, B, S, di, N)
+    from repro.kernels.selective_scan import chunk_len, scan_fwd
     y0, h0 = ref.selective_scan(dt, x, Bm, Cm, A)
-    y1, h1 = ssk(dt, x, Bm, Cm, A, block_d=bd, chunk=ch, interpret=True)
+    y1, h1, hs = scan_fwd(dt, x, Bm, Cm, A, chunk=ch, interpret=True)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y0), atol=1e-5)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h0), atol=1e-5)
+    # the state entering the last chunk is the recurrence's before it
+    L, c = chunk_len(S, ch), hs.shape[1] - 1
+    _, h_c = ref.selective_scan(*(t[:, :c * L] for t in (dt, x, Bm, Cm)), A)
+    np.testing.assert_allclose(np.asarray(hs[:, c, :, :di]),
+                               np.asarray(jnp.swapaxes(h_c, 1, 2)),
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("B,S,di,N,ch", [
+    (2, 37, 32, 8, 16),        # S padded to 48: the pad steps' cotangents
+    (1, 64, 256, 16, 32),      # two lane blocks, two chunks
+])
+def test_selective_scan_vjp_vs_ref(B, S, di, N, ch, rng):
+    """The backward kernel's cotangents of all five inputs, from those of
+    y and of the final state, against ``jax.vjp`` of the sequential
+    oracle."""
+    from repro.kernels import selective_scan as sk
+    args = _scan_inputs(rng, B, S, di, N)
+    gy = jnp.asarray(rng.randn(B, S, di), jnp.float32)
+    gh = jnp.asarray(rng.randn(B, di, N), jnp.float32)
+    _, vjp = jax.vjp(ref.selective_scan, *args)
+    want = vjp((gy, gh))
+    _, _, hs = sk.scan_fwd(*args, chunk=ch, interpret=True)
+    got = sk.scan_bwd(*args, hs, gy, gh, chunk=ch, interpret=True)
+    for name, g, w in zip(("dt", "x", "B", "C", "A"), got, want):
+        assert g.shape == w.shape, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-4, err_msg=name)
+
+
+def test_selective_scan_op_is_differentiable(rng, monkeypatch):
+    """``ops.selective_scan`` on the Pallas path (interpret mode) trains:
+    its custom VJP gives ``jax.grad`` the backward kernel's cotangents,
+    and the counters show both kernels were taken."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_FORCE", "interpret")
+    args = _scan_inputs(rng, 1, 24, 32, 8)
+    ops.reset_kernel_traces()
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)[0]))
+    got = jax.grad(loss(ops.selective_scan), argnums=range(5))(*args)
+    want = jax.grad(loss(ref.selective_scan), argnums=range(5))(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)
+    assert ops.KERNEL_TRACES["ssm_scan_pallas"] >= 1
+    assert ops.KERNEL_TRACES["ssm_scan_pallas_bwd"] >= 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_moe_gmm_vs_per_expert_einsum(dtype, rng):
+    """The grouped NF4 matmul and its transposed twin against a plain
+    per-expert einsum: an empty expert group, a group of several tiles,
+    and trailing tiles of zero rows past the groups (as
+    ``models.moe.group_by_expert`` leaves them) that must read zero."""
+    from repro.kernels import moe_gmm as gk
+    E, K, N, tm = 4, 256, 384, 16
+    qt = qlib.quantize(jnp.asarray(rng.randn(E, K, N), jnp.float32),
+                       bits=4, block=64, mode="nf4")
+    w = np.asarray(qlib.dequantize(qt, jnp.float32))
+    te = np.array([0, 0, 2, 3, 3, 3, 3, 3], np.int32)   # expert 1 empty
+    nt = 6
+    used = np.repeat(np.arange(8) < nt, tm)[:, None]
+    x = jnp.asarray(rng.randn(8 * tm, K) * used, dtype)
+    g = jnp.asarray(rng.randn(8 * tm, N) * used, dtype)
+    rows = np.repeat(te, tm)
+    xf, gf = np.asarray(x, np.float64), np.asarray(g, np.float64)
+    want = np.where(used, np.einsum("rk,rkn->rn", xf, w[rows]), 0.0)
+    want_t = np.where(used, np.einsum("rn,rkn->rk", gf, w[rows]), 0.0)
+    got = gk.moe_gmm(x, qt, jnp.asarray(te), block_k=128, block_n=128,
+                     interpret=True)
+    got_t = gk.moe_gmm_t(g, qt, jnp.asarray(te), block_k=128, block_n=128,
+                         interpret=True)
+    assert got.dtype == got_t.dtype == dtype
+    # f32: summation order; bf16: the output's own rounding
+    tol = 1e-5 if dtype == jnp.float32 else 2 ** -8
+    for a, b in ((got, want), (got_t, want_t)):
+        a = np.asarray(a, np.float64)
+        np.testing.assert_allclose(a, b, rtol=tol,
+                                   atol=tol * np.abs(b).max())
+        assert not a[~used[:, 0]].any()
+    np.testing.assert_allclose(
+        np.asarray(ref.moe_gmm(x, qt, jnp.asarray(te)), np.float64), want,
+        rtol=tol, atol=tol * np.abs(want).max())
 
 
 def test_selective_scan_ref_matches_mamba_chunked(rng):

@@ -145,6 +145,32 @@ def test_step_opens_forward_adam_and_remat(step_text):
                if "rematted_computation" in p and p.startswith("jit("))
 
 
+def test_jamba_step_opens_the_block_scopes():
+    """Jamba's period stack: the Mamba mixer sits in ``mamba``, the
+    expert feed-forward in ``moe`` with its ``router``, ``dispatch``,
+    ``experts`` and ``combine`` parts, beside the trunk's ``attn`` and
+    ``mlp``; each position is rematerialized on its own."""
+    cfg = get_reduced("jamba2-mini").replace(quant_bits=4, quant_mode="nf4",
+                                             quant_block=64)
+    model = build_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    tr = params["trainable"]
+    toks = jnp.zeros((1, 17), jnp.int32)
+    batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:],
+             "mask": jnp.ones((1, 16), jnp.float32)}
+    text = jax.jit(model.train_step).lower(
+        params["frozen"], tr, optim.adam_init(tr), batch).compile().as_text()
+    paths = [p for _, p in _op_paths(text) if p]
+    comps = set().union(*map(_components, paths))
+    for scope in ("forward", "adam", "rematted_computation", "attn", "mlp",
+                  "mamba", "moe", "router", "dispatch", "experts",
+                  "combine"):
+        assert scope in comps, scope
+    for part in ("router", "dispatch", "experts", "combine"):
+        assert all(f"moe/{part}/" in p for p in paths
+                   if part in _components(p)), part
+
+
 def test_runtime_spans_at_its_counters(monkeypatch):
     opened = []
     monkeypatch.setattr(runtime_lib, "span",

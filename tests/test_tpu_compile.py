@@ -249,3 +249,116 @@ def test_trunk_step_kernels_keep_names_under_phase_scopes(one_chip,
     assert {where(p) for p in calls["quant_matmul_t"]} == {"backward"}
     assert {where(p) for p in calls["flash_attention"]} == \
         {"forward", "remat", "backward"}
+
+
+# -- Jamba2-Mini: the cell's kernels and the whole step -----------------
+JAMBA_S, JAMBA_DI, JAMBA_N = 2048, 8192, 16     # tokens, d_inner, d_state
+JAMBA_ROWS = 8192          # round_up(2,048 x 2 + 16 x 255, 256) grouped rows
+
+
+def _stacked_qspec(E, K, N, sharding):
+    qt = qlib.qtensor_specs((E, K, N), jnp.bfloat16, bits=4, block=QBLOCK,
+                            mode="nf4")
+    return qlib.QTensor(q=_spec(qt.q.shape, qt.q.dtype, sharding),
+                        scales=_spec(qt.scales.shape, jnp.float32,
+                                     sharding),
+                        bits=qt.bits, mode=qt.mode, block=qt.block,
+                        out_dtype=qt.out_dtype, orig_shape=qt.orig_shape)
+
+
+def _custom_calls(text):
+    import re
+    calls = {}
+    for line in text.splitlines():
+        if "custom-call(" in line and "tpu_custom_call" in line:
+            name = line.split(" = ")[0].split()[-1].lstrip("%")
+            m = re.search(r'op_name="([^"]*)"', line)
+            calls.setdefault(name.rpartition(".")[0] or name, []).append(
+                m.group(1) if m else "")
+    return calls
+
+
+@pytest.mark.parametrize("K,N", [(4096, 14336), (14336, 4096)])
+@pytest.mark.parametrize("kernel", ["moe_gmm_fwd", "moe_gmm_dx"])
+def test_moe_gmm_compiles_at_the_cell_shapes(kernel, K, N, one_chip):
+    """The grouped NF4 expert matmul and its dx twin at Jamba2-Mini's
+    expert widths (16 experts, 8,192 grouped rows), each one instruction
+    under its own name."""
+    from repro.kernels import moe_gmm as gk
+    fn, cols = (gk.moe_gmm, K) if kernel == "moe_gmm_fwd" else \
+        (gk.moe_gmm_t, N)
+    text = jax.jit(fn).lower(
+        _spec((JAMBA_ROWS, cols), jnp.bfloat16, one_chip),
+        _stacked_qspec(16, K, N, one_chip),
+        _spec((JAMBA_ROWS // 256,), jnp.int32, one_chip)).compile().as_text()
+    assert list(_custom_calls(text)) == [kernel]
+
+
+@pytest.mark.parametrize("kernel", ["ssm_scan_fwd", "ssm_scan_bwd"])
+def test_selective_scan_compiles_at_the_cell_shapes(kernel, one_chip):
+    """The selective scan's forward and backward kernels at one
+    2,048-token sequence, d_inner 8,192, d_state 16."""
+    from repro.kernels import selective_scan as sk
+    f32 = lambda *sh: _spec(sh, jnp.float32, one_chip)
+    ins = (f32(1, JAMBA_S, JAMBA_DI), f32(1, JAMBA_S, JAMBA_DI),
+           f32(1, JAMBA_S, JAMBA_N), f32(1, JAMBA_S, JAMBA_N),
+           f32(JAMBA_DI, JAMBA_N))
+    if kernel == "ssm_scan_fwd":
+        text = jax.jit(sk.scan_fwd).lower(*ins).compile().as_text()
+    else:
+        text = jax.jit(sk.scan_bwd).lower(
+            *ins, f32(1, JAMBA_S // 128, JAMBA_N, JAMBA_DI),
+            f32(1, JAMBA_S, JAMBA_DI),
+            f32(1, JAMBA_DI, JAMBA_N)).compile().as_text()
+    assert list(_custom_calls(text)) == [kernel]
+
+
+def test_jamba_step_fits_and_keeps_kernel_names(one_chip, monkeypatch):
+    """The cell's whole train step (Jamba2-Mini's 8-layer period at
+    published widths, NF4, 1 x 2,048 tokens) with the Pallas path the
+    chip takes: the compiler plans it under the chip's 16 GB; every
+    kernel keeps the instruction name its roofline reader matches and
+    sits under its block's scope; the counters show the scan's backward
+    kernel and both grouped matmuls were taken (no autodiff of the
+    chunked scan, no dequantized expert stack)."""
+    from repro.configs import get_config
+    from repro.core import optim
+    from repro.kernels import ops as kops
+    from repro.models import build_model
+    monkeypatch.setattr(kops, "_use_pallas", lambda: True)
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    cfg = get_config("jamba2-mini").replace(
+        n_layers=8, quant_bits=4, quant_mode="nf4", quant_block=QBLOCK,
+        lora_rank=16, lora_alpha=32.0, dtype="bfloat16",
+        trainable_dtype="float32", adapter_heads=32, remat=True)
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip),
+                            tree)
+
+    tok = _spec((1, JAMBA_S), jnp.int32, one_chip)
+    batch = {"tokens": tok, "labels": tok,
+             "mask": _spec((1, JAMBA_S), jnp.float32, one_chip)}
+    kops.reset_kernel_traces()
+    compiled = jax.jit(model.train_step, donate_argnums=(1, 2)).lower(
+        on_chip(params["frozen"]), on_chip(params["trainable"]),
+        on_chip(jax.eval_shape(optim.adam_init, params["trainable"])),
+        batch).compile()
+    mem = compiled.memory_analysis()
+    planned = mem.argument_size_in_bytes + mem.temp_size_in_bytes + \
+        mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert planned < 16e9, planned
+    calls = _custom_calls(compiled.as_text())
+    assert set(calls) == {"lora_matmul", "quant_matmul_t", "flash_attention",
+                          "ssm_scan_fwd", "ssm_scan_bwd", "moe_gmm_fwd",
+                          "moe_gmm_dx"}
+    for name in ("ssm_scan_pallas_bwd", "moe_gmm_pallas", "moe_gmm_t_pallas"):
+        assert kops.KERNEL_TRACES.get(name, 0) > 0, name
+    assert not any(k.endswith("_ref") for k in kops.KERNEL_TRACES)
+    assert all("/mamba/" in p for n in ("ssm_scan_fwd", "ssm_scan_bwd")
+               for p in calls[n])
+    assert all("/moe/experts/" in p for n in ("moe_gmm_fwd", "moe_gmm_dx")
+               for p in calls[n])
+    assert any("/transpose(jvp(forward))/" in p for p in calls["moe_gmm_dx"])
